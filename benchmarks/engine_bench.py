@@ -46,11 +46,12 @@ by the per-scenario renderer columns, rendered as their own tables):
   out per row (first call) so the ratio measures execution, not
   tracing or load transients; bitwise ``sweep_identical`` checks on
   both the coarse-poll headline grid and the paper-default-poll grid;
-  and a host-device-count scaling row timing
-  ``simulation.sweep_sharded`` in subprocesses at
-  ``--xla_force_host_platform_device_count`` 1 vs 2 on a
+  and a device-count scaling row timing ``simulation.sweep_sharded``
+  over the first 1 vs 2 devices of this process on a
   heterogeneous-run-length grid (short-deadline lanes grouped on one
-  device stop costing while-loop iterations on the other);
+  device stop costing while-loop iterations on the other).  It needs
+  two devices: on the CPU, start the bench with
+  ``XLA_FLAGS=--xla_force_host_platform_device_count=2``;
 * ``_strategy_sweep`` -- the economic-broker section: the four DBC
   strategies plus the commodity/auction pricing models and plan-ahead
   dispatch as lanes of one ``engine.run_sweep_lanes`` call, with
@@ -58,10 +59,10 @@ by the per-scenario renderer columns, rendered as their own tables):
   ``engine.run(batch=1)`` reference) and ``table1_ordering`` (cost-min
   spends no more than time-min; time-min finishes no later) bits.
 
-The module enables the JAX persistent compilation cache
-(``jax_compilation_cache_dir``; override the directory with the
-``JAX_COMPILATION_CACHE_DIR`` env var) so repeated bench runs -- and
-the bench rows that share static shapes, which all reuse the single
+:func:`run` enables the JAX persistent compilation cache
+(:mod:`repro.compile_cache`: ``JAX_COMPILATION_CACHE_DIR`` when set,
+else ``.jax_cache`` in the checkout) so repeated bench runs -- and the
+bench rows that share static shapes, which all reuse the single
 module-level jitted ``simulation._sweep_grid`` -- skip recompilation.
 
 Sized for the 1-core CPU container (the kernel routes through its XLA
@@ -73,15 +74,13 @@ from __future__ import annotations
 import json
 import math
 import os
-import subprocess
-import sys
-import textwrap
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compilation_cache
 from repro.core import engine, gridlet, resource, simulation, types
 from repro.kernels import event_scan as event_scan_mod
 
@@ -91,20 +90,6 @@ from .common import art_path
 REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), os.pardir)
 GOLDEN_PATH = os.path.join(REPO, "tests", "data",
                            "golden_pre_refactor.json")
-
-
-def enable_compilation_cache():
-    """Point jax at a persistent on-disk compilation cache (best
-    effort: older/newer jax releases differ in knob names)."""
-    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                               "/tmp/jax_cache")
-    for key, val in (("jax_compilation_cache_dir", cache_dir),
-                     ("jax_persistent_cache_min_compile_time_secs", 1.0),
-                     ("jax_persistent_cache_min_entry_size_bytes", 0)):
-        try:
-            jax.config.update(key, val)
-        except (AttributeError, ValueError):
-            pass
 
 
 def _deep_fleet():
@@ -153,6 +138,24 @@ SCENARIOS = (
         retry_limit=8, backoff_base=1.0, blacklist_cooldown=5.0),
      None, 2000.0, 22000.0, dict(suffix="_trunk")),
 )
+
+
+def scenario_case(spec):
+    """One ``SCENARIOS`` row -> (cell name, gridlets, fleet,
+    ``run_experiment`` keyword arguments without ``batch``) at its
+    bench size; ``net_cap=None`` auto-sizes the transfer table."""
+    n_users, n_jobs, scenario, fleet_fn, deadline, budget, extras = spec
+    extras = extras or {}
+    fleet = resource.wwg_fleet() if fleet_fn is None else fleet_fn()
+    g = gridlet.task_farm(jax.random.PRNGKey(3), n_jobs=n_jobs,
+                          n_users=n_users,
+                          in_bytes=extras.get("in_bytes", 0.0),
+                          out_bytes=extras.get("out_bytes", 0.0))
+    kw = dict(deadline=deadline, budget=budget, opt=types.OPT_COST,
+              n_users=n_users, scenario=scenario,
+              net_cap=None if extras.get("net") else 0)
+    name = f"engine_{n_users}u_{n_jobs}j" + extras.get("suffix", "")
+    return name, g, fleet, kw
 
 
 def _one(fleet, g, n_users, scenario, batch, deadline, budget,
@@ -217,7 +220,7 @@ _HOW_COUNTERS = ("n_steps", "n_spec", "n_scans", "n_reseeds",
                  "telemetry")
 
 
-def _results_identical(a, b) -> bool:
+def results_identical(a, b) -> bool:
     for name in a._fields:
         if name in _HOW_COUNTERS:
             continue
@@ -239,62 +242,59 @@ def _results_identical(a, b) -> bool:
 # all short lanes on one device, which then stops paying while-loop
 # iterations for the long lanes -- the convoy effect a single vmap
 # cannot avoid on any device count.
-_DEVICE_SCALING_CODE = """
-    import json, time
-    import jax, jax.numpy as jnp
-    from benchmarks import engine_bench
-    engine_bench.enable_compilation_cache()
-    from repro.core import gridlet, resource, simulation, types
-    fleet = engine_bench._deep_fleet()
+def device_scaling_grid():
+    """(gridlets, fleet, deadlines, budgets, n_users) of the
+    device-scaling sweep: deep fleet, 2 deadlines x 20 budgets."""
     g = gridlet.task_farm(jax.random.PRNGKey(3), n_jobs=256, n_users=4)
-    dls = jnp.asarray([2.0, 10000.0])
-    buds = jnp.linspace(150000.0, 500000.0, 20)
-    t0 = time.perf_counter()
-    r = simulation.sweep_sharded(g, fleet, dls, buds, types.OPT_COST, 4)
-    jax.block_until_ready(r.spent)
-    first = time.perf_counter() - t0
-    t0 = time.perf_counter()
-    r = simulation.sweep_sharded(g, fleet, dls, buds, types.OPT_COST, 4)
-    jax.block_until_ready(r.spent)
-    wall = time.perf_counter() - t0
-    print(json.dumps({"devices": len(jax.devices()),
-                      "wall_s": wall,
-                      "compile_s": max(first - wall, 0.0),
-                      "n_done": float(jnp.sum(r.n_done)),
-                      "spent": float(jnp.sum(r.spent))}))
-"""
+    return (g, _deep_fleet(), jnp.asarray([2.0, 10000.0]),
+            jnp.linspace(150000.0, 500000.0, 20), 4)
 
 
 def _device_scaling():
-    """Time ``sweep_sharded`` at 1 vs 2 host devices, each in its own
-    subprocess (``--xla_force_host_platform_device_count`` must be set
-    before jax initialises, and the bench parent keeps its single
-    device).  One steady run per device count -- each is a minute-scale
-    program, far above timer noise."""
-    rows = {}
+    """Time ``sweep_sharded`` over the first 1 and 2 devices of this
+    process (one process holds every device; on the CPU it starts with
+    ``--xla_force_host_platform_device_count=2``).  One steady run per
+    device count -- each is a minute-scale program on the CPU, far
+    above timer noise."""
+    devices = jax.devices()
+    if len(devices) < 2:
+        raise RuntimeError(
+            f"device scaling needs 2 devices, found {len(devices)}; on "
+            "the CPU set XLA_FLAGS=--xla_force_host_platform_device_count=2")
+    g, fleet, dls, buds, n_users = device_scaling_grid()
+    rows, res = {}, {}
     for n in (1, 2):
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n}"
-        env["PYTHONPATH"] = os.pathsep.join(
-            [os.path.join(REPO, "src"), REPO]
-            + [p for p in env.get("PYTHONPATH", "").split(os.pathsep)
-               if p])
-        env.setdefault("JAX_COMPILATION_CACHE_DIR", "/tmp/jax_cache")
-        r = subprocess.run([sys.executable, "-c",
-                            textwrap.dedent(_DEVICE_SCALING_CODE)],
-                           capture_output=True, text=True, env=env,
-                           timeout=1800, cwd=REPO)
-        if r.returncode != 0:
-            rows[f"dev{n}"] = {"error": r.stderr[-2000:]}
-            continue
-        rows[f"dev{n}"] = json.loads(r.stdout.strip().splitlines()[-1])
-    if all("wall_s" in rows.get(f"dev{n}", {}) for n in (1, 2)):
-        rows["device_speedup"] = (rows["dev1"]["wall_s"] /
-                                  rows["dev2"]["wall_s"])
-        rows["device_identical"] = bool(
-            rows["dev1"]["n_done"] == rows["dev2"]["n_done"] and
-            rows["dev1"]["spent"] == rows["dev2"]["spent"])
+        def call():
+            r = simulation.sweep_sharded(g, fleet, dls, buds,
+                                         types.OPT_COST, n_users,
+                                         devices=devices[:n])
+            jax.block_until_ready(r.spent)
+            return r
+        t0 = time.perf_counter()
+        call()
+        first = time.perf_counter() - t0
+        t0 = time.perf_counter()
+        res[n] = call()
+        wall = time.perf_counter() - t0
+        rows[f"dev{n}"] = {"devices": n, "wall_s": wall,
+                           "compile_s": max(first - wall, 0.0),
+                           "n_done": float(jnp.sum(res[n].n_done)),
+                           "spent": float(jnp.sum(res[n].spent))}
+    rows["device_speedup"] = rows["dev1"]["wall_s"] / rows["dev2"]["wall_s"]
+    rows["device_identical"] = results_identical(res[1], res[2])
     return rows
+
+
+def sweep_grid():
+    """(gridlets, fleet, deadlines, budgets, coarse-poll scenario,
+    n_users) of the sweep section: 20 users x 25 jobs on the WWG fleet,
+    a 2 x 2 deadline x budget grid."""
+    return (gridlet.task_farm(jax.random.PRNGKey(3), n_jobs=25,
+                              n_users=20),
+            resource.wwg_fleet(), jnp.asarray([1500.0, 2000.0]),
+            jnp.asarray([15000.0, 22000.0]),
+            simulation.Scenario(sched_min_period=10.0, sched_frac=0.05),
+            20)
 
 
 def _sweep_bench():
@@ -323,11 +323,7 @@ def _sweep_bench():
     Also: a bitwise identity check over every "what" field per
     scenario; a single-device ``sweep_sharded`` identity check on the
     same grid; and the 1-vs-2-device scaling rows."""
-    fleet = resource.wwg_fleet()
-    g = gridlet.task_farm(jax.random.PRNGKey(3), n_jobs=25, n_users=20)
-    deadlines = jnp.asarray([1500.0, 2000.0])
-    budgets = jnp.asarray([15000.0, 22000.0])
-    coarse = simulation.Scenario(sched_min_period=10.0, sched_frac=0.05)
+    g, fleet, deadlines, budgets, coarse, n_users = sweep_grid()
     out = {"grid": "20u/25j, 2x2 deadline x budget, "
                    "sched_min_period=10 sched_frac=0.05"}
 
@@ -338,7 +334,8 @@ def _sweep_bench():
         for tag, kw in kws.items():
             t0 = time.perf_counter()
             r = simulation.sweep(g, fleet, deadlines, budgets,
-                                 types.OPT_COST, 20, scenario=scen, **kw)
+                                 types.OPT_COST, n_users, scenario=scen,
+                                 **kw)
             jax.block_until_ready(r.spent)
             first[tag] = time.perf_counter() - t0
             res[tag] = r
@@ -346,7 +343,7 @@ def _sweep_bench():
             for tag, kw in kws.items():
                 t0 = time.perf_counter()
                 r = simulation.sweep(g, fleet, deadlines, budgets,
-                                     types.OPT_COST, 20, scenario=scen,
+                                     types.OPT_COST, n_users, scenario=scen,
                                      **kw)
                 jax.block_until_ready(r.spent)
                 walls[tag].append(time.perf_counter() - t0)
@@ -360,16 +357,51 @@ def _sweep_bench():
         out[f"supersteps_{tag}"] = int(np.asarray(res[tag].n_steps).sum())
     out["batch"] = engine.DEFAULT_BATCH
     out["batch_speedup"] = out["wall_s_ref"] / out["wall_s_sweep"]
-    out["sweep_identical"] = _results_identical(res["ref"], res["sweep"])
+    out["sweep_identical"] = results_identical(res["ref"], res["sweep"])
     res_p, med_p, _ = measure(None)
     out["batch_speedup_paper_polls"] = med_p["ref"] / med_p["sweep"]
-    out["sweep_identical_paper_polls"] = _results_identical(
+    out["sweep_identical_paper_polls"] = results_identical(
         res_p["ref"], res_p["sweep"])
     sh = simulation.sweep_sharded(g, fleet, deadlines, budgets,
-                                  types.OPT_COST, 20, scenario=coarse)
-    out["sharded_identical"] = _results_identical(res["sweep"], sh)
+                                  types.OPT_COST, n_users, scenario=coarse,
+                                  devices=jax.devices()[:1])
+    out["sharded_identical"] = results_identical(res["sweep"], sh)
     out["device_scaling"] = _device_scaling()
     return out
+
+
+STRATEGY_DEADLINE, STRATEGY_BUDGET = 2000.0, 22000.0
+
+
+def strategy_lanes():
+    """(gridlets, fleet, n_users, max_events, lane names, stacked
+    params) of the strategy section's seven policy/pricing lanes."""
+    fleet = resource.wwg_fleet()
+    n_users = 20
+    g = gridlet.task_farm(jax.random.PRNGKey(3), n_jobs=25,
+                          n_users=n_users)
+    max_events = simulation._max_events(g.n, n_users, STRATEGY_DEADLINE,
+                                        1.0)
+    lanes_sc = (
+        ("cost", simulation.Scenario(policy=types.OPT_COST)),
+        ("time", simulation.Scenario(policy=types.OPT_TIME)),
+        ("cost_time", simulation.Scenario(policy=types.OPT_COST_TIME)),
+        ("none", simulation.Scenario(policy=types.OPT_NONE)),
+        ("cost_commodity", simulation.Scenario(
+            policy=types.OPT_COST, pricing_model="commodity",
+            market_period=60.0, market_gain=0.25)),
+        ("cost_auction", simulation.Scenario(
+            policy=types.OPT_COST, pricing_model="auction",
+            auction_period=60.0, seed=5)),
+        ("cost_plan", simulation.Scenario(policy=types.OPT_COST,
+                                          plan_ahead=True)),
+    )
+    ps = [simulation._scenario_params(fleet, STRATEGY_DEADLINE,
+                                      STRATEGY_BUDGET, types.OPT_COST,
+                                      n_users, sc)
+          for _, sc in lanes_sc]
+    p_lanes = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *ps)
+    return g, fleet, n_users, max_events, [n for n, _ in lanes_sc], p_lanes
 
 
 def _strategy_sweep():
@@ -391,30 +423,7 @@ def _strategy_sweep():
       cost-minimisation spends no more than time-minimisation, and
       time-minimisation finishes no later than cost-minimisation.
     """
-    fleet = resource.wwg_fleet()
-    n_users = 20
-    g = gridlet.task_farm(jax.random.PRNGKey(3), n_jobs=25,
-                          n_users=n_users)
-    deadline, budget = 2000.0, 22000.0
-    max_events = simulation._max_events(g.n, n_users, deadline, 1.0)
-    lanes_sc = (
-        ("cost", simulation.Scenario(policy=types.OPT_COST)),
-        ("time", simulation.Scenario(policy=types.OPT_TIME)),
-        ("cost_time", simulation.Scenario(policy=types.OPT_COST_TIME)),
-        ("none", simulation.Scenario(policy=types.OPT_NONE)),
-        ("cost_commodity", simulation.Scenario(
-            policy=types.OPT_COST, pricing_model="commodity",
-            market_period=60.0, market_gain=0.25)),
-        ("cost_auction", simulation.Scenario(
-            policy=types.OPT_COST, pricing_model="auction",
-            auction_period=60.0, seed=5)),
-        ("cost_plan", simulation.Scenario(policy=types.OPT_COST,
-                                          plan_ahead=True)),
-    )
-    ps = [simulation._scenario_params(fleet, deadline, budget,
-                                      types.OPT_COST, n_users, sc)
-          for _, sc in lanes_sc]
-    p_lanes = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *ps)
+    g, fleet, n_users, max_events, names, p_lanes = strategy_lanes()
     f = jax.jit(lambda pp: engine.run_sweep_lanes(
         g, fleet, pp, n_users, max_events, batch=engine.DEFAULT_BATCH))
     t0 = time.perf_counter()
@@ -426,16 +435,17 @@ def _strategy_sweep():
     jax.block_until_ready(r.spent)
     wall = time.perf_counter() - t0
     out = {"grid": f"20u/25j wwg, 7 policy/pricing lanes, "
-                   f"deadline={deadline:.0f} budget={budget:.0f}",
+                   f"deadline={STRATEGY_DEADLINE:.0f} "
+                   f"budget={STRATEGY_BUDGET:.0f}",
            "wall_s": wall, "compile_s": max(first - wall, 0.0),
            "batch": engine.DEFAULT_BATCH, "lanes": {}}
     identical = True
-    for i, (name, _) in enumerate(lanes_sc):
+    for i, name in enumerate(names):
         ref = engine.run(
             g, fleet, jax.tree_util.tree_map(lambda x: x[i], p_lanes),
             n_users, max_events, batch=1)
         lane = jax.tree_util.tree_map(lambda a: a[i], r)
-        identical = identical and _results_identical(ref, lane)
+        identical = identical and results_identical(ref, lane)
         identical = identical and (int(np.asarray(ref.n_steps)) +
                                    int(np.asarray(ref.n_spec))
                                    < max_events)
@@ -460,15 +470,11 @@ def run():
     except OSError:
         golden = {}
     report, out = {}, []
-    for n_users, n_jobs, scenario, fleet_fn, deadline, budget, extras \
-            in SCENARIOS:
+    for spec in SCENARIOS:
+        n_users, n_jobs, scenario, fleet_fn, deadline, budget, extras = spec
         extras = extras or {}
-        fleet = resource.wwg_fleet() if fleet_fn is None else fleet_fn()
-        g = gridlet.task_farm(jax.random.PRNGKey(3), n_jobs=n_jobs,
-                              n_users=n_users,
-                              in_bytes=extras.get("in_bytes", 0.0),
-                              out_bytes=extras.get("out_bytes", 0.0))
-        net_cap = None if extras.get("net") else 0  # None = auto-size
+        name, g, fleet, kw = scenario_case(spec)
+        net_cap = kw["net_cap"]
         r, wall, compile_s = _one(fleet, g, n_users, scenario,
                                   engine.DEFAULT_BATCH, deadline, budget,
                                   net_cap=net_cap)
@@ -521,7 +527,7 @@ def run():
             "overflow": int(np.asarray(r.overflow)),
             "truncated": bool(np.asarray(r.truncated)),
             "telemetry_identical": bool(
-                _results_identical(r, r_tel)
+                results_identical(r, r_tel)
                 and r_tel.telemetry is not None
                 and int(np.asarray(r_tel.telemetry.n)) > 0),
         }
@@ -538,7 +544,6 @@ def run():
         cell.update(roofline.bench_row(
             r_pad, j_cap, engine.DEFAULT_BATCH,
             int(np.asarray(r.n_scans)), wall))
-        name = f"engine_{n_users}u_{n_jobs}j" + extras.get("suffix", "")
         if extras.get("suffix") == "_fail":
             cell["scenario"] = {"mtbf": float(np.asarray(scenario.mtbf)),
                                 "mttr": float(np.asarray(scenario.mttr)),

@@ -126,13 +126,6 @@ def render(report: dict, baseline: dict | None = None) -> str:
                       f"**{ds['device_speedup']:.2f}x** | identical "
                       "across device counts: "
                       f"{'yes' if ds.get('device_identical') else '**NO**'}"]
-        else:
-            err = next((ds[k].get("error") for k in ("dev1", "dev2")
-                        if isinstance(ds.get(k), dict)
-                        and "error" in ds[k]), None)
-            if err:
-                lines += ["", "Device-scaling rows failed to run: "
-                          f"`{err.splitlines()[-1] if err else ''}`"]
     return "\n".join(lines)
 
 

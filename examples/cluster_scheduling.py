@@ -20,6 +20,7 @@ import os
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compilation_cache
 from repro.core import gridlet, resource, simulation, types
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -130,4 +131,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     main()

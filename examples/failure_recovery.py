@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compilation_cache
 from repro.core import gridlet, reservation, resource, simulation, types
 
 
@@ -133,4 +134,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     main()

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compilation_cache
 from repro.core import engine, gridlet, resource, simulation, types
 
 
@@ -116,4 +117,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     main()

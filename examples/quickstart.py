@@ -26,6 +26,7 @@ import sys
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compilation_cache
 from repro.core import economy, gridlet, resource, simulation, types
 
 
@@ -34,7 +35,10 @@ def main():
     budget = float(sys.argv[2]) if len(sys.argv) > 2 else 12000.0
 
     fleet = resource.wwg_fleet()
-    farm = gridlet.task_farm(jax.random.PRNGKey(7), n_jobs=200)
+    # the expected output above was recorded with the
+    # non-partitionable threefry, JAX's default PRNG before 0.5
+    with jax.threefry_partitionable(False):
+        farm = gridlet.task_farm(jax.random.PRNGKey(7), n_jobs=200)
     total_mi = float(farm.length_mi.sum())
 
     print(f"fleet: {fleet.r} resources, "
@@ -50,7 +54,7 @@ def main():
                                     budget=budget, opt=types.OPT_COST)
 
     per = np.asarray(res.per_resource_done[0], int)
-    cost_mi = np.asarray(fleet.cost_per_mi())
+    cost_mi = np.asarray(fleet.cost_per_mi)
     print("resource  PEs  G$/s   MIPS  gridlets")
     for r in range(fleet.r):
         print(f"R{r:<8d} {int(fleet.num_pe[r]):3d} "
@@ -75,4 +79,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     main()

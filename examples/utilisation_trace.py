@@ -32,6 +32,7 @@ import sys
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compilation_cache
 from repro.core import gridlet, resource, simulation, telemetry, types
 
 
@@ -102,4 +103,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compilation_cache()
     main()

@@ -141,7 +141,7 @@ def min_affordable_cost(g, fleet, n_users: int, price=None,
     min_mi = jax.ops.segment_min(
         jnp.where(undispatched, g.length_mi, INF), g.user,
         num_segments=n_users)
-    per_mi = fleet.cost_per_mi() if price is None else price
+    per_mi = fleet.cost_per_mi if price is None else price
     return min_mi * per_mi.min()
 
 
@@ -175,7 +175,7 @@ def _measure(state, fleet, params, n_users: int):
         fleet.num_pe - jnp.where(plan, 0, reserved),
         0).astype(jnp.float32)                                   # MIPS
     # Trading (Table 2 metric) off the POSTED per-MI price: bitwise
-    # fleet.cost_per_mi() until a pricing round moves it.
+    # fleet.cost_per_mi until a pricing round moves it.
     cost_per_mi = state.price                                    # [R]
 
     ones = jnp.ones((g.n,), jnp.float32)

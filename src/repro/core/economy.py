@@ -19,7 +19,7 @@ remain available -- both properties are asserted in tests.
 
 Pricing models
 --------------
-``fleet.cost_per_mi()`` (the Table 2 G$/MI trading metric) is the
+``fleet.cost_per_mi`` (the Table 2 G$/MI trading metric) is the
 *base* (advertised) price; the engine carries the *posted* per-MI price
 in ``SimState.price`` and the MARKET / AUCTION event sources
 (engine._make_sources) move it.  Prices live in per-MI units so the
@@ -105,14 +105,14 @@ def t_max(fleet, total_mi, registered=None):
 
 
 def c_min(fleet, total_mi, registered=None):
-    cpm = fleet.cost_per_mi()
+    cpm = fleet.cost_per_mi
     if registered is not None:
         cpm = jnp.where(registered, cpm, jnp.inf)
     return total_mi * cpm.min()
 
 
 def c_max(fleet, total_mi, registered=None):
-    cpm = fleet.cost_per_mi()
+    cpm = fleet.cost_per_mi
     if registered is not None:
         cpm = jnp.where(registered, cpm, -jnp.inf)
     return total_mi * cpm.max()

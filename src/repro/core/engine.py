@@ -394,7 +394,7 @@ class SimState:
                                #     trace (rows < ptr already applied)
     rng_key: jax.Array         # PRNG key for the MTBF/MTTR streams
     price: jax.Array           # f32[R] posted G$/MI trading metric
-                               #     (== fleet.cost_per_mi() until a
+                               #     (== fleet.cost_per_mi until a
                                #     pricing round moves it; per-MI so
                                #     the broker never divides a carried
                                #     array by an invariant in-loop --
@@ -1375,7 +1375,7 @@ def _make_sources(fleet, params, n_users, ctx):
                                     num_segments=n_resources)
         demand = n_res / jnp.maximum(fleet.num_pe.astype(jnp.float32),
                                      1.0)
-        base = jnp.asarray(fleet.cost_per_mi(), jnp.float32)
+        base = jnp.asarray(fleet.cost_per_mi, jnp.float32)
         newp = econ_mod.commodity_reprice(state.price, base, demand,
                                           params.market_gain,
                                           params.price_floor,
@@ -1399,7 +1399,7 @@ def _make_sources(fleet, params, n_users, ctx):
         # identity and every fired round consumes exactly one split.
         key, kbid = jax.random.split(state.auction_key)
         key = jnp.where(due, key, state.auction_key)
-        base = jnp.asarray(fleet.cost_per_mi(), jnp.float32)
+        base = jnp.asarray(fleet.cost_per_mi, jnp.float32)
         newp = econ_mod.auction_round(kbid, base, params.price_floor,
                                       params.price_cap)
         return replace(
@@ -2452,7 +2452,7 @@ def init_state(gridlets, fleet, n_users: int, first_sched: float = 0.0,
         trace_ptr=jnp.asarray(0, jnp.int32),
         rng_key=key,
         price=jnp.broadcast_to(
-            jnp.asarray(fleet.cost_per_mi(), jnp.float32), (fleet.r,)),
+            jnp.asarray(fleet.cost_per_mi, jnp.float32), (fleet.r,)),
         next_market=next_market,
         next_auction=next_auction,
         auction_key=auction_key,

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .types import SPACE_SHARED, TIME_SHARED, FCFS, pytree_dataclass
 
@@ -31,6 +32,8 @@ class Fleet:
     base_load: jax.Array     # f32: [0,1) background (non-grid) load factor
     weekend_load: jax.Array  # f32: additional weekend load factor
     baud_rate: jax.Array     # f32: bytes / time-unit to+from this resource
+    cost_per_mi: jax.Array   # f32: G$ per MI -- the broker's resource-trading
+                             #      metric (Table 2), cost_per_sec / mips_per_pe
 
     @property
     def r(self) -> int:
@@ -44,14 +47,18 @@ class Fleet:
         """Aggregate advertised MIPS per resource."""
         return self.mips_per_pe * self.num_pe.astype(jnp.float32)
 
-    def cost_per_mi(self) -> jax.Array:
-        """G$ per MI -- the broker's resource-trading metric (Table 2)."""
-        return self.cost_per_sec / self.mips_per_pe
-
 
 def make_fleet(num_pe, mips_per_pe, cost_per_sec, policy,
                queue_policy=None, time_zone=None, base_load=None,
                weekend_load=None, baud_rate=None) -> Fleet:
+    """Build a fleet on the host from per-resource values (scalars
+    broadcast to every resource).
+
+    The G$/MI price is divided here, once, in numpy: no traced division
+    of it remains, so a fleet passed to ``jax.jit`` as an argument and
+    one captured as a constant (which XLA folds on the host) carry the
+    same bits on every backend.
+    """
     num_pe = jnp.asarray(num_pe, jnp.int32)
     r = num_pe.shape[0]
 
@@ -60,16 +67,20 @@ def make_fleet(num_pe, mips_per_pe, cost_per_sec, policy,
             x = default
         return jnp.broadcast_to(jnp.asarray(x, dtype), (r,)).astype(dtype)
 
+    mips_per_pe = arr(mips_per_pe, None)
+    cost_per_sec = arr(cost_per_sec, None)
     return Fleet(
         num_pe=num_pe,
-        mips_per_pe=arr(mips_per_pe, None),
-        cost_per_sec=arr(cost_per_sec, None),
+        mips_per_pe=mips_per_pe,
+        cost_per_sec=cost_per_sec,
         policy=arr(policy, None, jnp.int32),
         queue_policy=arr(queue_policy, FCFS, jnp.int32),
         time_zone=arr(time_zone, 0.0),
         base_load=arr(base_load, 0.0),
         weekend_load=arr(weekend_load, 0.0),
         baud_rate=arr(baud_rate, 9600.0),  # GridSimTags.DEFAULT_BAUD_RATE
+        cost_per_mi=jnp.asarray(np.asarray(cost_per_sec)
+                                / np.asarray(mips_per_pe)),
     )
 
 

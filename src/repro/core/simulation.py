@@ -19,6 +19,8 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import Mesh, PartitionSpec as P
 
 from . import economy, engine, gridlet
 from .types import DONE, OPT_COST
@@ -264,6 +266,21 @@ def run_experiment(gridlets_batch, fleet, deadline, budget,
     positive row capacity records per-superstep time series into
     ``ExperimentResult.telemetry`` (see :mod:`repro.core.telemetry`).
     Purely observational -- results are bitwise identical on or off."""
+    params, max_events, max_jobs, net_cap = experiment_args(
+        gridlets_batch, fleet, deadline, budget, opt, n_users,
+        max_events, scenario, net_cap)
+    res = engine.run(gridlets_batch, fleet, params, n_users, max_events,
+                     max_jobs=max_jobs, batch=batch, net_cap=net_cap,
+                     telemetry=telemetry)
+    return summarize(res, params, n_users, fleet.r, max_events)
+
+
+def experiment_args(gridlets_batch, fleet, deadline, budget, opt=OPT_COST,
+                    n_users: int = 1, max_events: int | None = None,
+                    scenario: Scenario | None = None,
+                    net_cap: int | None = 0):
+    """The engine arguments :func:`run_experiment` resolves: (params,
+    max_events, max_jobs, net_cap), the last three static."""
     params = _scenario_params(fleet, deadline, budget, opt, n_users,
                               scenario)
     if net_cap is None:
@@ -271,10 +288,8 @@ def run_experiment(gridlets_batch, fleet, deadline, budget,
     if max_events is None:
         horizon = float(jnp.max(params.deadline)) * 2.0 + 100.0
         max_events = _max_events(gridlets_batch.n, n_users, horizon, 1.0)
-    res = engine.run(gridlets_batch, fleet, params, n_users, max_events,
-                     max_jobs=safe_max_jobs(gridlets_batch, params, fleet),
-                     batch=batch, net_cap=net_cap, telemetry=telemetry)
-    return summarize(res, params, n_users, fleet.r, max_events)
+    return (params, max_events,
+            safe_max_jobs(gridlets_batch, params, fleet), net_cap)
 
 
 def run_experiment_factors(gridlets_batch, fleet, d_factor, b_factor,
@@ -421,9 +436,10 @@ def sweep_sharded(gridlets_batch, fleet, deadlines, budgets,
     so lanes that finish early stop costing while-loop iterations on
     *other* devices (the single-vmap convoy effect).  Inputs are passed
     as replicated operands (no closure capture) and the flattened
-    deadline/budget vectors are donated.  Falls back to ``pmap`` when
-    ``shard_map`` is unavailable.  Results are bit-for-bit identical to
-    :func:`sweep` (asserted by tests/test_sweep_engine.py).
+    deadline/budget vectors are donated.  One device runs the same
+    lane layout under a plain ``jit``.  Results are bit-for-bit
+    identical to :func:`sweep` (asserted by
+    tests/test_sweep_engine.py).
     """
     deadlines = jnp.asarray(deadlines, jnp.float32)
     budgets = jnp.asarray(budgets, jnp.float32)
@@ -460,26 +476,14 @@ def sweep_sharded(gridlets_batch, fleet, deadlines, budgets,
                               select_free=select_free)
         return jax.vmap(one)(dd_l, bb_l)
 
-    out = None
     if n_dev > 1:
-        try:
-            import numpy as np
-            from jax.experimental.shard_map import shard_map
-            from jax.sharding import Mesh, PartitionSpec as P
-            mesh = Mesh(np.asarray(devices), ("s",))
-            fn = shard_map(run_lanes, mesh=mesh,
+        mesh = Mesh(np.asarray(devices), ("s",))
+        fn = jax.shard_map(run_lanes, mesh=mesh,
                            in_specs=(P(), P(), P(), P("s"), P("s")),
-                           out_specs=P("s"), check_rep=False)
-            out = jax.jit(fn, donate_argnums=(3, 4))(
-                gridlets_batch, fleet, template, dd, bb)
-        except (ImportError, AttributeError):
-            fn = jax.pmap(run_lanes, in_axes=(None, None, None, 0, 0),
-                          devices=devices)
-            out = fn(gridlets_batch, fleet, template,
-                     dd.reshape(n_dev, -1), bb.reshape(n_dev, -1))
-            out = jax.tree_util.tree_map(
-                lambda x: x.reshape((s_pad,) + x.shape[2:]), out)
-    if out is None:     # single device: plain jit, same lane layout
+                           out_specs=P("s"), check_vma=False)
+        out = jax.jit(fn, donate_argnums=(3, 4))(
+            gridlets_batch, fleet, template, dd, bb)
+    else:               # single device: plain jit, same lane layout
         out = jax.jit(run_lanes)(gridlets_batch, fleet, template, dd, bb)
     return jax.tree_util.tree_map(
         lambda x: x[:s].reshape((d_grid, b_grid) + x.shape[1:]), out)

@@ -88,6 +88,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BIG = 3.0e38
 INF = float("inf")
@@ -159,19 +160,27 @@ def _bitonic_exchange(arrays, lane, stride, size):
     on ``(arrays[0], arrays[1])``; the rest ride along as payload.
 
     Element ``i`` pairs with ``i ^ stride`` -- reached with two lane
-    rolls and a select, so the whole network lowers to VPU register
-    traffic (no gathers).  ``size`` is the current bitonic block length
-    (ascending where ``i & size == 0``); both may be traced scalars
-    (the stage schedule runs under lax.scan).
+    rotations (``pltpu.roll``: Mosaic's ``tpu.dynamic_rotate`` in a
+    kernel, ``jnp.roll`` elsewhere) and a select, so the whole network
+    lowers to VPU register traffic (no gathers).  ``stride`` and
+    ``size`` (the current bitonic block length, ascending where
+    ``i & size == 0``) are static ints.  Which rotation brings in the
+    partner is read off the rotated lane index itself, so the stage
+    holds whichever way the hardware rotates.
     """
-    upper = (lane & stride) != 0          # I am the higher lane of my pair
-    asc = (lane & size) == 0              # my block sorts ascending
-    partner = [jnp.where(upper, jnp.roll(a, stride, axis=-1),
-                         jnp.roll(a, -stride, axis=-1)) for a in arrays]
+    n = lane.shape[-1]
+    axis = lane.ndim - 1
+    fwd = pltpu.roll(lane, stride, axis)
+    from_fwd = fwd == (lane ^ stride)     # this rotation brings my partner
+    partner = [jnp.where(from_fwd, pltpu.roll(a, stride, axis),
+                         pltpu.roll(a, n - stride, axis)) for a in arrays]
     k, tk, pk, ptk = arrays[0], arrays[1], partner[0], partner[1]
     mine_gt = (k > pk) | ((k == pk) & (tk > ptk))
     partner_gt = (pk > k) | ((pk == k) & (ptk > tk))
-    take = jnp.where(upper == asc, partner_gt, mine_gt)
+    # lower lane of an ascending pair (or upper of a descending one)
+    # keeps the smaller element: take the partner's when it is smaller
+    keep_min = ((lane & stride) == 0) == ((lane & size) == 0)
+    take = (keep_min & mine_gt) | (~keep_min & partner_gt)
     return [jnp.where(take, p, a) for a, p in zip(arrays, partner)]
 
 
@@ -179,31 +188,22 @@ def _bitonic_sort(arrays):
     """Bitonic-sort ``arrays`` (lex keys ``arrays[0], arrays[1]`` +
     payload) along the last axis, which must be a power of two.
 
-    The O(log^2 J) stage schedule runs under two nested
-    ``lax.fori_loop``s with the (size, stride) pair derived from the
-    loop indices by scalar shifts, so the compare-exchange body
-    compiles exactly once (an unrolled network blows XLA CPU compile
-    time up by minutes at J >= 512, and Pallas kernels cannot capture
-    a constant schedule array), at the cost of the rolls taking traced
-    shifts.
+    The O(log^2 J) stage schedule is unrolled at trace time, so every
+    rotation has a static shift (Mosaic lowers no traced-shift
+    ``jnp.roll``): 55 stages at J = 1024, 66 at J = 2048.
     """
     n = arrays[0].shape[-1]
     assert n & (n - 1) == 0, "bitonic width must be a power of two"
     lane = jax.lax.broadcasted_iota(jnp.int32, arrays[0].shape,
                                     arrays[0].ndim - 1)
-    n_outer = max(n.bit_length() - 1, 0)            # log2(n)
-
-    def outer(k, arrs):
-        size = jnp.int32(2) << k                    # 2, 4, ..., n
-
-        def inner(j, arrs):
-            stride = size >> (j + 1)                # size/2, ..., 1
-            return tuple(_bitonic_exchange(list(arrs), lane, stride,
-                                           size))
-
-        return jax.lax.fori_loop(0, k + 1, inner, arrs)
-
-    return list(jax.lax.fori_loop(0, n_outer, outer, tuple(arrays)))
+    size = 2
+    while size <= n:
+        stride = size // 2
+        while stride:
+            arrays = _bitonic_exchange(arrays, lane, stride, size)
+            stride //= 2
+        size *= 2
+    return arrays
 
 
 def _bitonic_rank(rem, tie, valid):
@@ -220,11 +220,12 @@ def _bitonic_rank(rem, tie, valid):
     """
     key = jnp.where(valid, rem, BIG)
     tkey = jnp.where(valid, tie, BIG)
-    col = jax.lax.broadcasted_iota(jnp.float32, rem.shape, rem.ndim - 1)
+    # int32 iota cast after: Mosaic has no float iota
+    col = jax.lax.broadcasted_iota(jnp.int32, rem.shape,
+                                   rem.ndim - 1).astype(jnp.float32)
     _, _, scol = _bitonic_sort([key, tkey, col])
-    pos = jax.lax.broadcasted_iota(jnp.float32, rem.shape, rem.ndim - 1)
     zero = jnp.zeros_like(scol)
-    _, _, rank = _bitonic_sort([scol, zero, pos])
+    _, _, rank = _bitonic_sort([scol, zero, col])
     return rank, key, tkey
 
 

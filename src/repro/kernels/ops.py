@@ -1,10 +1,17 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``interpret=None`` auto-selects: compiled Pallas on TPU backends,
-interpret mode elsewhere (this container is CPU-only, so tests and
-benches run the kernels through the interpreter; the TPU lowering is the
-TARGET and is exercised by .lower() in the dry-run-adjacent kernel
-tests).
+The simulator's kernels (``event_scan``, ``event_scan_slab``,
+``link_scan``, ``event_frontier``) route by ``interpret``:
+
+* ``None`` (what the engine passes): the compiled Pallas kernel on a
+  TPU backend, the vectorised XLA fallback on any other backend -- so
+  on the CPU the engine never runs the Pallas interpreter;
+* ``True``: the Pallas kernel in interpret mode (the kernel tests);
+* ``False``: the compiled Pallas kernel.
+
+The backend test is :func:`_on_tpu`, the one place the choice is made.
+tests/test_tpu_compile.py compiles the kernels, and the engine through
+them, for a described TPU v5e.
 
 Every wrapper body runs under a ``jax.named_scope`` carrying the
 kernel's public name, so device profiles (``jax.profiler.trace`` /
@@ -24,10 +31,14 @@ from . import flash_attention as _flash
 from . import ssd_scan as _ssd
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def _auto_interpret(interpret):
     if interpret is not None:
         return interpret
-    return jax.default_backend() != "tpu"
+    return not _on_tpu()
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -77,7 +88,7 @@ def event_scan(remaining, mips_eff, num_pe, tie=None, policy=None,
                                          pe_blocked=pe_blocked,
                                          row_ok=row_ok,
                                          with_rank=with_rank, rank=rank)
-        if interpret is None and jax.default_backend() != "tpu":
+        if interpret is None and not _on_tpu():
             return _event.event_scan_xla(remaining, mips_eff, num_pe,
                                          tie=tie, policy=policy,
                                          pe_blocked=pe_blocked,
@@ -86,7 +97,7 @@ def event_scan(remaining, mips_eff, num_pe, tie=None, policy=None,
         return _event.event_scan(remaining, mips_eff, num_pe, tie=tie,
                                  policy=policy, pe_blocked=pe_blocked,
                                  row_ok=row_ok, block_r=block_r,
-                                 interpret=_auto_interpret(interpret),
+                                 interpret=bool(interpret),
                                  with_rank=with_rank)
 
 
@@ -117,7 +128,7 @@ def event_scan_slab(remaining, mips_eff, num_pe, k=8, tie=None,
     against).  Wave 0 is bitwise identical either way.
     """
     with jax.named_scope("event_scan_slab"):
-        if interpret is None and jax.default_backend() != "tpu":
+        if interpret is None and not _on_tpu():
             return _event.event_scan_slab_xla(remaining, mips_eff,
                                               num_pe, k, tie=tie,
                                               policy=policy,
@@ -129,7 +140,7 @@ def event_scan_slab(remaining, mips_eff, num_pe, k=8, tie=None,
                                       pe_blocked=pe_blocked,
                                       row_ok=row_ok, live=live,
                                       block_r=block_r,
-                                      interpret=_auto_interpret(interpret),
+                                      interpret=bool(interpret),
                                       assoc=assoc)
 
 
@@ -150,12 +161,12 @@ def link_scan(remaining, baud, bg=None, tie=None, cap=None, *,
     path), Pallas interpret mode only on request.
     """
     with jax.named_scope("link_scan"):
-        if interpret is None and jax.default_backend() != "tpu":
+        if interpret is None and not _on_tpu():
             return _event.link_scan_xla(remaining, baud, bg=bg, tie=tie,
                                         cap=cap)
         return _event.link_scan(remaining, baud, bg=bg, tie=tie,
                                 cap=cap, block_l=block_l,
-                                interpret=_auto_interpret(interpret))
+                                interpret=bool(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("sizes", "interpret"))
@@ -172,7 +183,7 @@ def event_frontier(cand, sizes, cuts=None, *, interpret=None):
     XLA fallback on CPU hosts, Pallas interpret mode on request.
     """
     with jax.named_scope("event_frontier"):
-        if interpret is None and jax.default_backend() != "tpu":
+        if interpret is None and not _on_tpu():
             return _event.event_frontier_xla(cand, sizes, cuts=cuts)
         return _event.event_frontier(cand, sizes, cuts=cuts,
-                                     interpret=_auto_interpret(interpret))
+                                     interpret=bool(interpret))

@@ -12,11 +12,16 @@ The scenario is sized so several K_AUCTION rounds land inside the
 """
 import json
 import os
+import sys
 
 import jax
 import numpy as np
 
-from repro.core import des, engine, gridlet, resource, simulation, types
+from repro.core import des, engine, resource, simulation, types
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+from _golden_farm import golden_farm  # noqa: E402
 
 OUT = os.path.join(os.path.dirname(__file__), "golden_auction.json")
 
@@ -24,7 +29,7 @@ OUT = os.path.join(os.path.dirname(__file__), "golden_auction.json")
 def build_case():
     fleet = resource.make_fleet([2, 4], [300.0, 500.0], [2.0, 5.0],
                                 [types.TIME_SHARED, types.SPACE_SHARED])
-    g = gridlet.task_farm(jax.random.PRNGKey(6), n_jobs=10, n_users=2)
+    g = golden_farm("seed6_10x2")
     sc = simulation.Scenario(pricing_model="auction", auction_period=15.0,
                              seed=8)
     params = simulation._scenario_params(fleet, 400.0, 20_000.0,
@@ -35,7 +40,9 @@ def build_case():
 
 def main():
     g, fleet, params, max_jobs = build_case()
-    r = engine.run(g, fleet, params, 2, 4096, max_jobs=max_jobs, batch=1)
+    with jax.threefry_partitionable(False):   # the recorded bid stream
+        r = engine.run(g, fleet, params, 2, 4096, max_jobs=max_jobs,
+                       batch=1)
     tt, kind, who = (np.asarray(x) for x in r.trace)
     m = kind >= 0
     n_auction = int((kind[m] == des.K_AUCTION).sum())

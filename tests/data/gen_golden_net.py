@@ -10,19 +10,23 @@ tests assert both batch=1 and the default batch reproduce it bitwise.
 """
 import json
 import os
+import sys
 
-import jax
 import numpy as np
 
-from repro.core import engine, gridlet, resource, simulation, types
+from repro.core import engine, resource, simulation, types
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+from _golden_farm import golden_farm  # noqa: E402
 
 OUT = os.path.join(os.path.dirname(__file__), "golden_net_20u.json")
 
 
 def main():
     fleet = resource.wwg_fleet()
-    g = gridlet.task_farm(jax.random.PRNGKey(3), n_jobs=100, n_users=20,
-                          in_bytes=200_000.0, out_bytes=100_000.0)
+    g = golden_farm("seed3_100x20", in_bytes=200_000.0,
+                    out_bytes=100_000.0)
     sc = simulation.Scenario(baud_rate=28_000.0, bg_flows=1.0)
     params = simulation._scenario_params(fleet, 2000.0, 22000.0,
                                          types.OPT_COST, 20, sc)

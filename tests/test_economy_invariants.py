@@ -20,6 +20,7 @@ import pytest
 
 from repro.core import des, economy, engine, gridlet, resource, \
     simulation, types
+from _golden_farm import golden_farm
 
 MAX_EVENTS = 4096
 
@@ -128,7 +129,9 @@ def test_golden_auction_trace_pinned_across_batch():
     times, kinds, actors, spend, termination -- at batch=1 AND the
     default batch, pinning the auction source's event ordering, PRNG
     stream and price-driven dispatch decisions (regenerate with
-    tests/data/gen_golden_auction.py)."""
+    tests/data/gen_golden_auction.py).  The job lengths are committed
+    data; the bid stream is drawn inside the engine, so the runs use the
+    threefry variant it was recorded under."""
     import json
     import os
     with open(os.path.join(os.path.dirname(__file__), "data",
@@ -136,7 +139,7 @@ def test_golden_auction_trace_pinned_across_batch():
         gold = json.load(f)
     fleet = resource.make_fleet([2, 4], [300.0, 500.0], [2.0, 5.0],
                                 [types.TIME_SHARED, types.SPACE_SHARED])
-    g = gridlet.task_farm(jax.random.PRNGKey(6), n_jobs=10, n_users=2)
+    g = golden_farm("seed6_10x2")
     sc = simulation.Scenario(pricing_model="auction", auction_period=15.0,
                              seed=8)
     params = simulation._scenario_params(fleet, 400.0, 20_000.0,
@@ -146,8 +149,9 @@ def test_golden_auction_trace_pinned_across_batch():
         des.K_AUCTION) >= 3
     for batch in (1, None):
         kw = {} if batch is None else dict(batch=batch)
-        r = engine.run(g, fleet, params, 2, 4096, max_jobs=max_jobs,
-                       **kw)
+        with jax.threefry_partitionable(False):
+            r = engine.run(g, fleet, params, 2, 4096, max_jobs=max_jobs,
+                           **kw)
         tt, kind, who = (np.asarray(x) for x in r.trace)
         m = kind >= 0
         assert np.array_equal(tt[m],
@@ -183,7 +187,7 @@ def test_engine_prices_stay_clamped_under_pricing():
         pos = {s.kind: i for i, s in enumerate(sources)}
         kind = des.K_MARKET if model == "commodity" else des.K_AUCTION
         src = sources[pos[kind]]
-        base = np.asarray(fleet.cost_per_mi(), np.float32)
+        base = np.asarray(fleet.cost_per_mi, np.float32)
         lo = base * float(params.price_floor)
         hi = base * float(params.price_cap)
         now = 10.0

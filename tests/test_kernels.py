@@ -237,8 +237,9 @@ def test_event_scan_slab_wave0_is_event_scan(seed):
 
 
 def test_event_scan_slab_lowers_for_tpu_shapes():
-    """The slab kernel must trace/lower at fleet scale (R=256, J=128,
-    k=8) -- the TPU-target workload of the batched superstep engine."""
+    """The slab kernel must trace at fleet scale (R=256, J=128, k=8).
+    Interpret-mode shape tracing only: Mosaic never sees it (the
+    compiles for a described TPU are in tests/test_tpu_compile.py)."""
     r, j = 256, 128
     rem = jax.ShapeDtypeStruct((r, j), jnp.float32)
     v = jax.ShapeDtypeStruct((r,), jnp.float32)
@@ -370,7 +371,9 @@ def test_event_frontier_paths_agree(seed):
 def test_event_frontier_tpu_lane_shapes():
     """The engine's real layout -- per-row completion forecasts,
     per-resource failure/recovery streams, [N]-sized RETURN/ARRIVAL
-    segments, a scalar broker -- padded across TPU lane boundaries."""
+    segments, a scalar broker -- padded across TPU lane boundaries.
+    Runs in interpret mode: Mosaic never sees it (the compiles for a
+    described TPU are in tests/test_tpu_compile.py)."""
     rng = np.random.RandomState(0)
     sizes = (16, 11, 11, 6, 2000, 2000, 11, 1)
     c = sum(sizes)
@@ -480,8 +483,9 @@ def test_event_scan_slab_assoc_wave0_bitwise(seed):
 
 
 def test_event_scan_slab_assoc_lowers_for_tpu_shapes():
-    """Both slab formulations trace/lower at fleet scale (R=256, J=128,
-    k=8) and at the wide bitonic widths J = 512/1024."""
+    """Both slab formulations trace at fleet scale (R=256, J=128, k=8)
+    and at the wide bitonic widths J = 512/1024.  Interpret-mode shape
+    tracing only: Mosaic never sees it."""
     for j in (128, 512, 1024):
         rem = jax.ShapeDtypeStruct((256, j), jnp.float32)
         v = jax.ShapeDtypeStruct((256,), jnp.float32)

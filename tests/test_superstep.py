@@ -20,10 +20,11 @@ from repro.core import des, engine, gridlet, resource, simulation, types
 from repro.core.types import replace as treplace
 from repro.kernels import ops, ref
 from repro.kernels.event_scan import event_scan_xla
+from _golden_farm import golden_farm
+from benchmarks.table1 import ARRIVALS, LENGTHS, TRACES
 
 GOLDEN = json.load(open(os.path.join(os.path.dirname(__file__), "data",
                                      "golden_pre_refactor.json")))
-ARRIVALS = jnp.array([0.0, 4.0, 7.0])
 
 
 # ----------------------------------------------------------------------
@@ -31,7 +32,7 @@ ARRIVALS = jnp.array([0.0, 4.0, 7.0])
 # engine must reproduce the exact times, kinds and FIFO order.
 # ----------------------------------------------------------------------
 def _trace(policy, batch=engine.DEFAULT_BATCH):
-    g = gridlet.make_batch([10.0, 8.5, 9.5])
+    g = gridlet.make_batch(LENGTHS)
     fleet = resource.table1_resource(policy)
     res = engine.run_direct(g, fleet, 0, ARRIVALS, max_events=64,
                             batch=batch)
@@ -41,18 +42,10 @@ def _trace(policy, batch=engine.DEFAULT_BATCH):
                          who[m].tolist()))
 
 
-GOLDEN_TS_TRACE = [
-    (0.0, 2, 0), (4.0, 2, 1), (7.0, 2, 2),        # arrivals
-    (10.0, 0, 0), (10.0, 1, 0),                   # G1 done+returned
-    (14.0, 0, 1), (14.0, 1, 1),                   # G2
-    (18.0, 0, 2), (18.0, 1, 2),                   # G3
-]
-
-
 def test_time_shared_golden_trace():
     # kinds: 0=completion, 1=return, 2=arrival, 3=broker
     res, trace = _trace(types.TIME_SHARED, batch=1)
-    assert trace == GOLDEN_TS_TRACE
+    assert trace == TRACES[types.TIME_SHARED]
     # zero-delay returns fold into their completion superstep: 9 events
     # in 6 supersteps.
     assert int(res.n_events) == 9 and int(res.n_steps) == 6
@@ -64,7 +57,7 @@ def test_time_shared_golden_trace_batched():
     three completion supersteps (10/14/18: no arrival, broker or
     boundary can intervene) speculate into the t=7 arrival iteration."""
     res, trace = _trace(types.TIME_SHARED)          # default batch
-    assert trace == GOLDEN_TS_TRACE
+    assert trace == TRACES[types.TIME_SHARED]
     assert int(res.n_events) == 9
     assert int(res.n_steps) == 3 and int(res.n_spec) == 3
     assert int(res.overflow) == 0
@@ -72,12 +65,7 @@ def test_time_shared_golden_trace_batched():
 
 def test_space_shared_golden_trace():
     res, trace = _trace(types.SPACE_SHARED, batch=1)
-    assert trace == [
-        (0.0, 2, 0), (4.0, 2, 1), (7.0, 2, 2),
-        (10.0, 0, 0), (10.0, 1, 0),                   # G1 frees the PE
-        (12.5, 0, 1), (12.5, 1, 1),
-        (19.5, 0, 2), (19.5, 1, 2),                   # queued G3 last
-    ]
+    assert trace == TRACES[types.SPACE_SHARED]
     assert int(res.n_steps) == 6 and int(res.overflow) == 0
     # batched: same trace (queue admissions are speculation-safe: they
     # ride inside the completion superstep), half the iterations
@@ -109,7 +97,7 @@ def test_simultaneous_events_apply_in_one_superstep():
 def test_matches_pre_refactor_engine_results():
     ref_run = GOLDEN["1u_200j"]
     fleet = resource.wwg_fleet()
-    g = gridlet.task_farm(jax.random.PRNGKey(3), n_jobs=200, n_users=1)
+    g = golden_farm("seed3_200x1")
     r = simulation.run_experiment(g, fleet, deadline=2000.0,
                                   budget=22000.0, opt=types.OPT_COST,
                                   n_users=1)
@@ -225,7 +213,7 @@ def test_zero_rate_sources_reproduce_golden():
     any scenario (which itself must match the pre-refactor golden)."""
     ref_run = GOLDEN["20u_100j"]
     fleet = resource.wwg_fleet()
-    g = gridlet.task_farm(jax.random.PRNGKey(3), n_jobs=100, n_users=20)
+    g = golden_farm("seed3_100x20")
     kw = dict(deadline=2000.0, budget=22000.0, opt=types.OPT_COST,
               n_users=20)
     base = simulation.run_experiment(g, fleet, **kw)

@@ -288,3 +288,43 @@ def test_run_sweep_lanes_matches_per_lane_reference():
             3, me, batch=1)
         lane = jax.tree_util.tree_map(lambda x: x[i], lanes)
         assert_results_identical(one, lane, tag=f"lane{i} ")
+
+
+def test_fleet_price_per_mi_divided_on_host():
+    """The G$/MI price is a stored fleet field, divided once in numpy,
+    so no backend ever divides it inside a traced program."""
+    fleet = resource.wwg_fleet()
+    want = (np.asarray(fleet.cost_per_sec, np.float32)
+            / np.asarray(fleet.mips_per_pe, np.float32))
+    assert np.asarray(fleet.cost_per_mi).dtype == np.float32
+    assert np.array_equal(np.asarray(fleet.cost_per_mi), want)
+
+
+@pytest.mark.parametrize("captured", [True, False])
+def test_run_sweep_lanes_closure_or_args_match_engine_run(captured):
+    """Gridlets and fleet captured by the jitted lane call (constants
+    XLA may fold) or passed as arguments: every lane is bitwise equal to
+    its own engine.run(batch=1) -- policy and pricing lanes, whose
+    dispatch and spend read the fleet's G$/MI price."""
+    fleet = resource.wwg_fleet()
+    g = gridlet.task_farm(jax.random.PRNGKey(5), n_jobs=6, n_users=3)
+    me = simulation._max_events(g.n, 3, 2000.0, 1.0)
+    scs = (simulation.Scenario(policy=types.OPT_COST),
+           simulation.Scenario(policy=types.OPT_TIME),
+           simulation.Scenario(policy=types.OPT_COST,
+                               pricing_model="commodity",
+                               market_period=60.0, market_gain=0.25))
+    ps = [simulation._scenario_params(fleet, 2000.0, 9000.0,
+                                      types.OPT_COST, 3, sc) for sc in scs]
+    p_lanes = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *ps)
+    if captured:
+        lanes = jax.jit(lambda pp: engine.run_sweep_lanes(
+            g, fleet, pp, 3, me, batch=engine.DEFAULT_BATCH))(p_lanes)
+    else:
+        lanes = jax.jit(lambda gg, fl, pp: engine.run_sweep_lanes(
+            gg, fl, pp, 3, me, batch=engine.DEFAULT_BATCH))(g, fleet,
+                                                            p_lanes)
+    for i, p in enumerate(ps):
+        one = engine.run(g, fleet, p, 3, me, batch=1)
+        lane = jax.tree_util.tree_map(lambda x: x[i], lanes)
+        assert_results_identical(one, lane, tag=f"lane{i} ")
