@@ -421,42 +421,22 @@ def sweep(gridlets_batch, fleet, deadlines, budgets, opt=OPT_COST,
                        select_free=select_free)
 
 
-def sweep_sharded(gridlets_batch, fleet, deadlines, budgets,
-                  opt=OPT_COST, n_users: int = 1,
-                  max_events: int | None = None,
-                  scenario: Scenario | None = None,
-                  batch: int | None = None, net_cap: int | None = 0,
-                  select_free: bool = True, devices=None):
-    """:func:`sweep` with the scenario axis sharded across devices.
+@functools.partial(jax.jit, static_argnames=(
+    "n_users", "max_events", "max_jobs", "batch", "net_cap",
+    "select_free", "mesh"), donate_argnames=("dd", "bb"))
+def _sweep_lanes(gridlets_batch, fleet, template, dd, bb, n_users: int,
+                 max_events: int, max_jobs: int, batch: int, net_cap: int,
+                 select_free: bool, mesh: Mesh | None):
+    """Jitted flat-lane runner behind :func:`sweep_sharded`.
 
-    The [D, B] grid is flattened deadline-major into one scenario axis
-    of S = D*B lanes, padded up to a device multiple, and split across
-    ``devices`` (default: all of them) with ``shard_map`` -- each
-    device runs its contiguous slice of lanes as an independent vmap,
-    so lanes that finish early stop costing while-loop iterations on
-    *other* devices (the single-vmap convoy effect).  Inputs are passed
-    as replicated operands (no closure capture) and the flattened
-    deadline/budget vectors are donated.  One device runs the same
-    lane layout under a plain ``jit``.  Results are bit-for-bit
-    identical to :func:`sweep` (asserted by
-    tests/test_sweep_engine.py).
+    Module-level (not a per-call closure), like :func:`_sweep_grid`:
+    jit caches by function identity, so only then do repeated calls
+    with the same mesh and static arguments reuse one traced and
+    compiled program instead of re-tracing the whole engine.  ``mesh``
+    (hashable; equal for the same devices and axis names) splits the
+    lanes over its ``"s"`` axis; None runs the same lane layout on one
+    device without ``shard_map``.
     """
-    deadlines = jnp.asarray(deadlines, jnp.float32)
-    budgets = jnp.asarray(budgets, jnp.float32)
-    template, max_events, max_jobs, batch, net_cap = _sweep_statics(
-        gridlets_batch, fleet, deadlines, opt, n_users, max_events,
-        scenario, batch, net_cap, select_free)
-    d_grid, b_grid = deadlines.shape[0], budgets.shape[0]
-    s = d_grid * b_grid
-    devices = jax.devices() if devices is None else list(devices)
-    n_dev = max(1, len(devices))
-    s_pad = -(-s // n_dev) * n_dev
-    dd = jnp.repeat(deadlines, b_grid)   # deadline-major flatten [S]
-    bb = jnp.tile(budgets, d_grid)
-    if s_pad != s:   # pad with copies of the last lane (discarded below)
-        dd = jnp.concatenate([dd, jnp.broadcast_to(dd[-1:], (s_pad - s,))])
-        bb = jnp.concatenate([bb, jnp.broadcast_to(bb[-1:], (s_pad - s,))])
-
     def run_lanes(g, f, tmpl, dd_l, bb_l):
         if select_free:
             # Lane-batched engine loop per shard: each device's
@@ -476,14 +456,55 @@ def sweep_sharded(gridlets_batch, fleet, deadlines, budgets,
                               select_free=select_free)
         return jax.vmap(one)(dd_l, bb_l)
 
-    if n_dev > 1:
-        mesh = Mesh(np.asarray(devices), ("s",))
-        fn = jax.shard_map(run_lanes, mesh=mesh,
-                           in_specs=(P(), P(), P(), P("s"), P("s")),
-                           out_specs=P("s"), check_vma=False)
-        out = jax.jit(fn, donate_argnums=(3, 4))(
-            gridlets_batch, fleet, template, dd, bb)
-    else:               # single device: plain jit, same lane layout
-        out = jax.jit(run_lanes)(gridlets_batch, fleet, template, dd, bb)
+    if mesh is not None:
+        run_lanes = jax.shard_map(
+            run_lanes, mesh=mesh,
+            in_specs=(P(), P(), P(), P("s"), P("s")),
+            out_specs=P("s"), check_vma=False)
+    return run_lanes(gridlets_batch, fleet, template, dd, bb)
+
+
+def sweep_sharded(gridlets_batch, fleet, deadlines, budgets,
+                  opt=OPT_COST, n_users: int = 1,
+                  max_events: int | None = None,
+                  scenario: Scenario | None = None,
+                  batch: int | None = None, net_cap: int | None = 0,
+                  select_free: bool = True, devices=None):
+    """:func:`sweep` with the scenario axis sharded across devices.
+
+    The [D, B] grid is flattened deadline-major into one scenario axis
+    of S = D*B lanes, padded up to a device multiple, and split across
+    ``devices`` (default: all of them) with ``shard_map`` -- each
+    device runs its contiguous slice of lanes as an independent vmap,
+    so lanes that finish early stop costing while-loop iterations on
+    *other* devices (the single-vmap convoy effect).  Inputs are passed
+    as replicated operands (no closure capture) and the flattened
+    deadline/budget vectors are donated.  One device runs the same
+    lane layout without ``shard_map``.  The program is built once per
+    mesh and static shape (:func:`_sweep_lanes`).  Results are bit-for-bit
+    identical to :func:`sweep` (asserted by
+    tests/test_sweep_engine.py).
+    """
+    deadlines = jnp.asarray(deadlines, jnp.float32)
+    budgets = jnp.asarray(budgets, jnp.float32)
+    template, max_events, max_jobs, batch, net_cap = _sweep_statics(
+        gridlets_batch, fleet, deadlines, opt, n_users, max_events,
+        scenario, batch, net_cap, select_free)
+    d_grid, b_grid = deadlines.shape[0], budgets.shape[0]
+    s = d_grid * b_grid
+    devices = jax.devices() if devices is None else list(devices)
+    n_dev = max(1, len(devices))
+    s_pad = -(-s // n_dev) * n_dev
+    dd = jnp.repeat(deadlines, b_grid)   # deadline-major flatten [S]
+    bb = jnp.tile(budgets, d_grid)
+    if s_pad != s:   # pad with copies of the last lane (discarded below)
+        dd = jnp.concatenate([dd, jnp.broadcast_to(dd[-1:], (s_pad - s,))])
+        bb = jnp.concatenate([bb, jnp.broadcast_to(bb[-1:], (s_pad - s,))])
+
+    mesh = Mesh(np.asarray(devices), ("s",)) if n_dev > 1 else None
+    out = _sweep_lanes(gridlets_batch, fleet, template, dd, bb,
+                       n_users=n_users, max_events=max_events,
+                       max_jobs=max_jobs, batch=batch, net_cap=net_cap,
+                       select_free=select_free, mesh=mesh)
     return jax.tree_util.tree_map(
         lambda x: x[:s].reshape((d_grid, b_grid) + x.shape[1:]), out)

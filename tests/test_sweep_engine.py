@@ -6,6 +6,7 @@ axis (simulation.sweep_sharded) matches the unsharded sweep exactly,
 including under a forced multi-device host; and the slab kernels'
 ``live`` masked no-op gate is a bitwise no-op on all three backends.
 """
+import contextlib
 import os
 import subprocess
 import sys
@@ -44,6 +45,22 @@ def assert_results_identical(a, b, tag=""):
         for i, (x, y) in enumerate(zip(la, lb)):
             assert np.array_equal(np.asarray(x), np.asarray(y)), \
                 f"{tag}{name}[leaf {i}] differs"
+
+
+@contextlib.contextmanager
+def count_traces():
+    """Count jaxpr traces inside the block: the event the benchmark's
+    ``traces_in_window`` counts."""
+    n = [0]
+
+    def listener(name, _secs, **_):
+        if name == "/jax/core/compile/jaxpr_trace_duration":
+            n[0] += 1
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        yield n
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
 
 
 def _case(seed, with_failures, with_net):
@@ -140,6 +157,82 @@ def test_sweep_sharded_matches_under_forced_devices():
             for x, y in zip(jax.tree_util.tree_leaves(getattr(a, name)),
                             jax.tree_util.tree_leaves(getattr(b, name))):
                 assert np.array_equal(np.asarray(x), np.asarray(y)), name
+        print("OK")
+    """
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["PYTHONPATH"] = os.path.join(REPO, "src")
+    r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
+                       capture_output=True, text=True, env=env,
+                       timeout=600)
+    assert r.returncode == 0, f"stderr:\n{r.stderr[-3000:]}"
+    assert "OK" in r.stdout
+
+
+def test_sweep_sharded_builds_once_per_static_shape():
+    """Repeated sweep_sharded calls with the same shapes and statics
+    reuse one program (no trace at all on the second call); another
+    max_events builds exactly one more.  Answers == sweep, bitwise."""
+    fleet = resource.wwg_fleet()
+    g = gridlet.task_farm(jax.random.PRNGKey(9), n_jobs=4, n_users=1)
+    dls, buds = [600.0, 1300.0], [5000.0, 12000.0]
+    ref_res = simulation.sweep(g, fleet, dls, buds, types.OPT_COST, 1)
+    n0 = simulation._sweep_lanes._cache_size()
+    a = simulation.sweep_sharded(g, fleet, dls, buds, types.OPT_COST, 1)
+    assert simulation._sweep_lanes._cache_size() == n0 + 1
+    with count_traces() as traces:
+        b = simulation.sweep_sharded(g, fleet, dls, buds, types.OPT_COST, 1)
+        jax.block_until_ready(b)
+    assert traces[0] == 0
+    assert simulation._sweep_lanes._cache_size() == n0 + 1
+    me = simulation._max_events(g.n, 1, 2 * max(dls) + 100.0, 1.0) + 64
+    simulation.sweep_sharded(g, fleet, dls, buds, types.OPT_COST, 1,
+                             max_events=me)
+    assert simulation._sweep_lanes._cache_size() == n0 + 2
+    assert_results_identical(ref_res, a, "first: ")
+    assert_results_identical(ref_res, b, "second: ")
+
+
+def test_sweep_sharded_builds_once_per_mesh_under_forced_devices():
+    """With 8 forced host devices: a second call on all 8 traces
+    nothing, a call on the first 4 builds its own program (the mesh is
+    part of the key), and every answer == sweep, bitwise -- padding
+    included (S = 6 -> 8 lanes on 8 devices, 6 -> 8 on 4)."""
+    code = """
+        import jax, numpy as np
+        from repro.core import gridlet, resource, simulation, types
+        assert len(jax.devices()) == 8
+        traces = [0]
+        def listener(name, _secs, **_):
+            if name == "/jax/core/compile/jaxpr_trace_duration":
+                traces[0] += 1
+        jax.monitoring.register_event_duration_secs_listener(listener)
+        fleet = resource.wwg_fleet()
+        g = gridlet.task_farm(jax.random.PRNGKey(5), n_jobs=6, n_users=2)
+        dls = [500.0, 1000.0, 2000.0]
+        buds = [6000.0, 14000.0]
+        a = simulation.sweep(g, fleet, dls, buds, types.OPT_COST, 2)
+        run = lambda devs: jax.block_until_ready(simulation.sweep_sharded(
+            g, fleet, dls, buds, types.OPT_COST, 2, devices=devs))
+        n0 = simulation._sweep_lanes._cache_size()
+        outs = [run(jax.devices())]
+        assert simulation._sweep_lanes._cache_size() == n0 + 1
+        traces[0] = 0
+        outs.append(run(jax.devices()))
+        assert traces[0] == 0, traces[0]
+        assert simulation._sweep_lanes._cache_size() == n0 + 1
+        outs.append(run(jax.devices()[:4]))
+        assert traces[0] > 0
+        assert simulation._sweep_lanes._cache_size() == n0 + 2
+        skip = {"n_steps", "n_spec", "n_scans", "n_reseeds"}
+        for i, b in enumerate(outs):
+            for name in a._fields:
+                if name in skip:
+                    continue
+                for x, y in zip(jax.tree_util.tree_leaves(getattr(a, name)),
+                                jax.tree_util.tree_leaves(getattr(b, name))):
+                    assert np.array_equal(np.asarray(x), np.asarray(y)), \
+                        (i, name)
         print("OK")
     """
     env = dict(os.environ)
